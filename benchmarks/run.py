@@ -3,8 +3,9 @@
   PYTHONPATH=src python -m benchmarks.run            # everything
   PYTHONPATH=src python -m benchmarks.run --only table7 kernel
 
-Alongside the CSV, machine-readable JSON is written for the perf
-trajectories later PRs must not regress:
+A suite that raises is reported as a ``<tag>/ERROR`` row, the others still
+run, and the harness exits non-zero. Alongside the CSV, machine-readable
+JSON is written for the perf trajectories later PRs must not regress:
 
   BENCH_kernels.json — the kernel suite rows (written here)
   BENCH_trainer.json — fused-engine vs seed-loop steps/sec (written by
@@ -16,11 +17,12 @@ import argparse
 import json
 import sys
 import time
+import traceback
 
 JSON_SUITES = {"kernel": "BENCH_kernels.json"}
 
 
-def main(argv=None) -> None:
+def main(argv=None) -> int:
     ap = argparse.ArgumentParser()
     ap.add_argument("--only", nargs="*", default=None,
                     help="substring filters (e.g. table1 kernel trainer roofline)")
@@ -43,6 +45,7 @@ def main(argv=None) -> None:
     ]
 
     by_tag: dict = {}
+    failed = []
     print("name,us_per_call,derived")
     for tag, fn in suites:
         if args.only and not any(o in tag for o in args.only):
@@ -54,7 +57,9 @@ def main(argv=None) -> None:
                 by_tag.setdefault(tag, []).append(
                     {"name": name, "us_per_call": us, "derived": derived}
                 )
-        except Exception as e:  # report, keep the harness going
+        except Exception as e:  # report, run the other suites, exit non-zero
+            failed.append(tag)
+            traceback.print_exc()
             print(f"{tag}/ERROR,0.0,{type(e).__name__}:{e}", file=sys.stdout)
             # mark the JSON too, so a truncated suite can't pose as complete
             by_tag.setdefault(tag, []).append(
@@ -68,7 +73,10 @@ def main(argv=None) -> None:
             with open(fname, "w") as f:
                 json.dump({"suite": tag, "rows": by_tag[tag]}, f, indent=2)
             print(f"# wrote {fname}", file=sys.stderr)
+    if failed:
+        print(f"# suites failed: {', '.join(failed)}", file=sys.stderr)
+    return 1 if failed else 0
 
 
 if __name__ == "__main__":
-    main()
+    sys.exit(main())

@@ -30,8 +30,8 @@ class CNNConfig:
     # pre-pool activation never leaves the chip). Differentiable in e2e mode
     # via a jax.custom_vjp that backs onto the XLA reference.
     use_kernel: bool = False
-    # interpret=None auto-selects: real Mosaic lowering when a TPU/GPU
-    # backend is present, Pallas interpreter on CPU. CAVEAT: interpret mode
+    # interpret=None auto-selects: Mosaic lowering on a TPU backend, the
+    # Pallas interpreter anywhere else. CAVEAT: interpret mode
     # is a Python emulation — correct but slow; on CPU prefer
     # use_kernel=False for throughput and keep the kernel for parity tests.
     interpret: Optional[bool] = None

@@ -164,6 +164,8 @@ class FusedEngine:
         self._init_state, _ = make_spatio_temporal_step(adapter, tc, opt, mesh=mesh)
         self._runners: Dict[Tuple[int, str], Callable] = {}
         self._epochs_done = 0
+        # the epoch mode the last fit ran ("scan" | "stepwise")
+        self.epoch_mode: Optional[str] = None
 
     def init(self, key):
         self._root = key
@@ -212,7 +214,7 @@ class FusedEngine:
 
     def run(self, state, shards, *, epochs, steps_per_epoch, eval_fn=None):
         assert len(shards) == self.tc.n_clients
-        mode = self.mode or _auto_epoch_mode(shards, self.tc)
+        mode = self.epoch_mode = self.mode or _auto_epoch_mode(shards, self.tc)
         run_epoch = self._runner(steps_per_epoch, mode)
         data_x, data_y, lens = device_put_shards(shards)
         state, data_x, data_y = self._place(state, data_x, data_y)

@@ -60,7 +60,6 @@ from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.flatten_util import ravel_pytree
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
@@ -231,14 +230,14 @@ def _shard_banked_forward(fwd_banked, mesh: Mesh, client_axis: str):
     device. On a 1-device mesh this is a bit-exact no-op — the per-shard body
     is the same vmapped jaxpr over the full client axis."""
     spec = P(client_axis)
-    sharded = shard_map(
+    sharded = jax.shard_map(
         fwd_banked, mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
-        check_rep=False,
+        check_vma=False,
     )
     if len(mesh.axis_names) == 1:
         return sharded
 
-    # 2-D ("clients", "model") grids: ``check_rep=False`` skips verifying
+    # 2-D ("clients", "model") grids: ``check_vma=False`` skips verifying
     # that operands are REPLICATED over the unmentioned model axis, and the
     # unchecked full-to-shard conversion reads whatever is locally resident
     # — if GSPMD laid an operand out sharded over "model" (its right under

@@ -17,6 +17,7 @@ Cholesterol: LDL-C follows the Friedewald relation LDL = TC - HDL - TG/5 + eps
 """
 from __future__ import annotations
 
+import zlib
 from typing import Dict, Tuple
 
 import numpy as np
@@ -68,7 +69,9 @@ def make_mura(n: int, hw: int = 224, seed: int = 0, part: str = "wrist"):
     """X-ray-like bone images; positive = fracture (dark discontinuity)."""
     total, pos, neg = MURA_BODY_PARTS[part]
     p_pos = pos / total  # per-part class balance from paper Table 2
-    rng = np.random.default_rng(seed + hash(part) % (1 << 16))
+    # crc32, not hash(): str hashes are salted per process, and a seed must
+    # draw the same images in every process
+    rng = np.random.default_rng(seed + zlib.crc32(part.encode()) % (1 << 16))
     x = np.zeros((n, hw, hw, 1), np.float32)
     y = (rng.random(n) < p_pos).astype(np.float32)
     yy, xx = np.mgrid[0:hw, 0:hw].astype(np.float32)

@@ -1,3 +1,11 @@
-# OPTIONAL layer. Add <name>.py (or .cu) + ops.py + ref.py ONLY
-# for compute hot-spots the paper itself optimizes with a custom
-# kernel. Leave this package empty if the paper has none.
+"""Pallas TPU kernels for the paper's hot spots, each with a pure-jnp
+``ref.py`` oracle and a jitted ``ops.py`` wrapper."""
+import jax
+
+
+def resolve_interpret(interpret):
+    """None = auto: lower through Mosaic on a TPU backend, run the (slow but
+    correct) Pallas interpreter anywhere else, where Mosaic cannot lower."""
+    if interpret is None:
+        return jax.default_backend() != "tpu"
+    return interpret

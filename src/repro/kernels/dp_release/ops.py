@@ -10,8 +10,8 @@ check against. Noise is a constant of the release: its cotangent is zero.
 Switches (surfaced on ``repro.privacy.DPConfig``):
   * ``use_kernel`` — False falls back to the pure-jnp reference (XLA path;
     the default, and the fastest choice on CPU).
-  * ``interpret`` — None auto-selects real Mosaic lowering on TPU/GPU and
-    the Pallas interpreter on CPU.
+  * ``interpret`` — None auto-selects Mosaic lowering on a TPU and the
+    Pallas interpreter anywhere else.
 """
 from __future__ import annotations
 
@@ -20,7 +20,8 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
-from repro.kernels.dp_release.kernel import dp_release_pallas, resolve_interpret
+from repro.kernels import resolve_interpret
+from repro.kernels.dp_release.kernel import dp_release_pallas
 from repro.kernels.dp_release.ref import dp_release_ref
 
 

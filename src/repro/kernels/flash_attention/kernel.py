@@ -18,6 +18,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import resolve_interpret
+
 NEG_INF = -1e30
 
 
@@ -65,8 +67,9 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
 
 def flash_attention_pallas(q, k, v, *, causal: bool = True, window: int = 0,
                            q_block: int = 128, kv_block: int = 128,
-                           interpret: bool = True):
+                           interpret: bool | None = None):
     """q, k, v: [BH, S, hd] (GQA folded by ops.py). Returns [BH, S, hd]."""
+    interpret = resolve_interpret(interpret)
     BH, S, hd = q.shape
     scale = 1.0 / (hd ** 0.5)
     q_block = min(q_block, S)

@@ -14,7 +14,7 @@ from repro.kernels.flash_attention.ref import flash_attention_ref
                                    "use_kernel", "interpret"))
 def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
                     q_block: int = 128, kv_block: int = 128,
-                    use_kernel: bool = True, interpret: bool = True):
+                    use_kernel: bool = True, interpret: bool | None = None):
     """GQA flash attention. q: [B, S, H, hd]; k, v: [B, S, KV, hd].
 
     Folds (B, H) into the kernel's leading grid dim; GQA groups share k/v by

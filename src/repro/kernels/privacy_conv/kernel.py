@@ -20,6 +20,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels import resolve_interpret
+
 
 def _kernel(x_ref, w_ref, b_ref, noise_ref, o_ref, *, tile_h: int, W: int,
             noise_scale: float):
@@ -43,14 +45,6 @@ def _kernel(x_ref, w_ref, b_ref, noise_ref, o_ref, *, tile_h: int, W: int,
     if noise_scale > 0.0:
         pooled = pooled + noise_scale * noise_ref[0].astype(jnp.float32)
     o_ref[0] = pooled.astype(o_ref.dtype)
-
-
-def resolve_interpret(interpret):
-    """None = auto: compile for real on TPU/GPU backends, fall back to the
-    (slow but correct) Pallas interpreter on CPU, where Mosaic can't lower."""
-    if interpret is None:
-        return jax.default_backend() not in ("tpu", "gpu")
-    return interpret
 
 
 def privacy_conv_pallas(x, w, b, noise, *, noise_scale: float = 0.0,

@@ -9,8 +9,8 @@ tests check against.
 
 Switches (also surfaced on ``CNNConfig``):
   * ``use_kernel`` — False falls back to the pure-jnp reference (XLA path).
-  * ``interpret`` — None auto-selects real Mosaic lowering on TPU/GPU and
-    the Pallas interpreter on CPU. Interpret mode is a Python emulation:
+  * ``interpret`` — None auto-selects Mosaic lowering on a TPU and the
+    Pallas interpreter anywhere else. Interpret mode is a Python emulation:
     numerically faithful but slow, so CPU throughput runs should prefer
     ``use_kernel=False`` and keep the kernel path for parity checks.
 """
@@ -21,7 +21,8 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
-from repro.kernels.privacy_conv.kernel import privacy_conv_pallas, resolve_interpret
+from repro.kernels import resolve_interpret
+from repro.kernels.privacy_conv.kernel import privacy_conv_pallas
 from repro.kernels.privacy_conv.ref import privacy_conv_ref
 
 

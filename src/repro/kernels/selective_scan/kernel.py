@@ -20,6 +20,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import resolve_interpret
+
 
 def _kernel(u_ref, dt_ref, B_ref, C_ref, A_ref, D_ref, y_ref, h_scr, *,
             t_chunk: int):
@@ -51,11 +53,12 @@ def _kernel(u_ref, dt_ref, B_ref, C_ref, A_ref, D_ref, y_ref, h_scr, *,
 
 
 def selective_scan_pallas(u, dt, B, C, A, D, *, d_tile: int = 128,
-                          t_chunk: int = 64, interpret: bool = True):
+                          t_chunk: int = 64, interpret: bool | None = None):
     """u, dt: [Bsz, S, di]; B, C: [Bsz, S, st]; A: [di, st]; D: [di].
 
     Returns y [Bsz, S, di] = selective_scan(u) + D*u.
     """
+    interpret = resolve_interpret(interpret)
     Bsz, S, di = u.shape
     st = A.shape[1]
     d_tile = min(d_tile, di)

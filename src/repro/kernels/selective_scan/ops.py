@@ -11,7 +11,7 @@ from repro.kernels.selective_scan.ref import selective_scan_ref
 
 @partial(jax.jit, static_argnames=("d_tile", "t_chunk", "use_kernel", "interpret"))
 def selective_scan(u, dt, B, C, A, D, *, d_tile: int = 128, t_chunk: int = 64,
-                   use_kernel: bool = True, interpret: bool = True):
+                   use_kernel: bool = True, interpret: bool | None = None):
     """Mamba-1 selective state-space scan (see kernel.py for semantics)."""
     if use_kernel:
         return selective_scan_pallas(

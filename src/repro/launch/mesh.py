@@ -9,6 +9,15 @@ multi-pod = (pod=2, data=16, model=16) = 512 chips.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def _auto_mesh(shape, axes):
+    """``jax.make_mesh`` with every axis ``Auto``: the sharding rules
+    (``repro.sharding.logical``) constrain with ``P.UNCONSTRAINED`` and leave
+    layout to GSPMD, which an ``Explicit`` axis (the default) refuses."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(shape))
 
 
 def make_production_mesh(*, multi_pod: bool = False, shape=None):
@@ -18,7 +27,7 @@ def make_production_mesh(*, multi_pod: bool = False, shape=None):
     if shape is None:
         shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if len(shape) == 3 else ("data", "model")
-    return jax.make_mesh(tuple(shape), axes)
+    return _auto_mesh(shape, axes)
 
 
 def _check_divides(n_clients, axis_size: int, axis: str) -> None:
@@ -98,7 +107,7 @@ def make_host_mesh(model: int = 1):
     """Tiny mesh over whatever devices exist (CPU tests)."""
     n = len(jax.devices())
     assert n % model == 0
-    return jax.make_mesh((n // model, model), ("data", "model"))
+    return _auto_mesh((n // model, model), ("data", "model"))
 
 
 def data_axis_size(mesh) -> int:

@@ -30,6 +30,7 @@ import numpy as np
 
 from repro.configs import get_config
 from repro.data.lm import token_stream
+from repro.launch.compile_cache import configure_compile_cache
 from repro.models import model as model_lib
 from repro.models.transformer import ModelOptions
 
@@ -171,4 +172,5 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    configure_compile_cache()
     main()

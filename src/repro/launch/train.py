@@ -26,6 +26,7 @@ from repro.configs import get_config
 from repro.core import SplitSession, SplitTrainConfig
 from repro.core.distributed import llm_adapter
 from repro.data.lm import token_stream, token_windows
+from repro.launch.compile_cache import configure_compile_cache
 from repro.models.transformer import ModelOptions
 from repro.optim import adamw, linear_warmup_cosine
 from repro.privacy import DPConfig
@@ -112,4 +113,5 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    configure_compile_cache()
     main()

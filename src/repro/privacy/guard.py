@@ -80,8 +80,8 @@ class DPConfig:
     noise_scale: Optional[float] = None  # explicit σ override (legacy knob)
     quantize_bits: Optional[int] = None  # optional uniform quantization
     # use_kernel routes the clip+noise release through the fused Pallas
-    # kernel (repro.kernels.dp_release); interpret=None auto-selects real
-    # lowering on TPU/GPU, the Pallas interpreter on CPU (slow — CPU
+    # kernel (repro.kernels.dp_release); interpret=None auto-selects Mosaic
+    # lowering on a TPU, the Pallas interpreter anywhere else (slow — CPU
     # throughput runs should keep the default XLA path).
     use_kernel: bool = False
     interpret: Optional[bool] = None

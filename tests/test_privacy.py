@@ -218,6 +218,9 @@ def test_protocol_queue_stats_report_budget(chol_shards):
 @pytest.mark.parametrize("shape,clip,sigma", [
     ((4, 8, 8, 2), 1.0, 0.0), ((2, 16, 16, 4), 0.5, 0.1),
     ((8, 7), 2.0, 0.05),
+    # rows longer than one VMEM tile: divisible (2304 rows -> 2 tiles of
+    # 1152) and padded (2305 rows -> 2 tiles of 2048)
+    ((2, 64, 64, 72), 1.0, 0.1), ((2, 2304 * 128 + 5), 1.0, 0.1),
 ])
 def test_dp_release_kernel_matches_ref(shape, clip, sigma):
     from repro.kernels.dp_release.kernel import dp_release_pallas

@@ -32,6 +32,18 @@ def test_mura_class_balance_matches_table2():
     assert 0.15 < y.mean() < 0.40
 
 
+def test_mura_same_seed_same_images_in_every_process():
+    import subprocess
+    import sys
+    code = ("from repro.data.synthetic import make_mura; "
+            "x, y = make_mura(4, hw=16, seed=0); print(float(x.sum()), y.tolist())")
+    outs = [subprocess.run([sys.executable, "-c", code], capture_output=True,
+                           text=True, check=True,
+                           env={**os.environ, "PYTHONHASHSEED": s}).stdout
+            for s in ("1", "2")]
+    assert outs[0] == outs[1] and outs[0]
+
+
 def test_cholesterol_follows_friedewald():
     x, y = make_cholesterol(500, seed=0, normalize=False)
     tc, hdl, tg = x[:, 4], x[:, 5], x[:, 6]

@@ -62,18 +62,15 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax, dataclasses
 from repro.configs import get_config, SHAPES
 from repro.launch import steps as steps_lib
+from repro.launch.mesh import make_host_mesh
 shape = dataclasses.replace(SHAPES["train_4k"], seq_len=64, global_batch=8)
 cfg = get_config("llama3.2-1b").reduced()
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+mesh = make_host_mesh(model=2)
 low = steps_lib.build(cfg, shape, mesh)
 with mesh:
     compiled = jax.jit(low.fn, in_shardings=low.in_shardings,
                        out_shardings=low.out_shardings).lower(*low.args).compile()
 cost = compiled.cost_analysis()
-# cost_analysis() returns a dict on newer jaxlib, a one-element list of
-# dicts on older versions
-if isinstance(cost, (list, tuple)):
-    cost = cost[0] if cost else {}
 print("OK", float(cost.get("flops", 0)) > 0)
 """
     r = subprocess.run([sys.executable, "-c", script], capture_output=True,
