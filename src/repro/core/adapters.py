@@ -43,12 +43,19 @@ def banked_client_forward(adapter: SplitAdapter, guard=None) -> Callable[..., An
     With an enabled ``repro.privacy.PrivacyGuard`` the release (clip →
     Gaussian mechanism → quantize) runs INSIDE the vmapped client forward,
     on a fold-in of each client's per-step key — so the guard vectorizes
-    over the client axis and shard_maps with it under a device mesh."""
+    over the client axis and shard_maps with it under a device mesh.
+
+    The privacy layer's ops carry the ``privacy_layer`` named scope, the
+    guard's its own ``guard`` scope (op_name metadata only)."""
+    def layer(bank, x, key):
+        with jax.named_scope("privacy_layer"):
+            return adapter.client_forward(bank, x, key)
+
     if guard is None or not guard.enabled:
-        return jax.vmap(adapter.client_forward)
+        return jax.vmap(layer)
 
     def fwd(bank, x, key):
-        return guard(guard.key_for(key), adapter.client_forward(bank, x, key))
+        return guard(guard.key_for(key), layer(bank, x, key))
 
     return jax.vmap(fwd)
 

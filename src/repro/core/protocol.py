@@ -54,13 +54,18 @@ def make_client_release_fwd(adapter: SplitAdapter,
     guarded at the cut when the ``PrivacyGuard`` is enabled. Parameters are
     arguments, so ONE compiled function serves every client of a fleet —
     the engines prebuild it and hand it to each ``SplitClient`` instead of
-    paying a fresh trace per client per ``fit``."""
+    paying a fresh trace per client per ``fit``. The privacy layer's ops
+    carry the ``privacy_layer`` named scope, the guard's ``guard``."""
     guard = guard if guard is not None else PrivacyGuard()
-    if guard.enabled:
-        return jax.jit(
-            lambda p, x, k: guard(guard.key_for(k), adapter.client_forward(p, x, k))
-        )
-    return jax.jit(lambda p, x, k: adapter.client_forward(p, x, k))
+
+    def release(p, x, k):
+        with jax.named_scope("privacy_layer"):
+            f = adapter.client_forward(p, x, k)
+        return guard(guard.key_for(k), f) if guard.enabled else f
+
+    # a lambda, so that the compiled program keeps its name (``jit__lambda``),
+    # by which the benchmark's layer map finds it
+    return jax.jit(lambda p, x, k: release(p, x, k))
 
 
 def make_fleet_release_fwd(adapter: SplitAdapter,
@@ -87,7 +92,8 @@ def make_fleet_release_fwd(adapter: SplitAdapter,
     guard = guard if guard is not None else PrivacyGuard()
 
     def one(bank, x, key):
-        f = adapter.client_forward(bank, x, key)
+        with jax.named_scope("privacy_layer"):
+            f = adapter.client_forward(bank, x, key)
         return guard(guard.key_for(key), f) if guard.enabled else f
 
     vfwd = jax.vmap(one)

@@ -216,18 +216,23 @@ class FusedEngine:
         assert len(shards) == self.tc.n_clients
         mode = self.epoch_mode = self.mode or _auto_epoch_mode(shards, self.tc)
         run_epoch = self._runner(steps_per_epoch, mode)
-        data_x, data_y, lens = device_put_shards(shards)
-        state, data_x, data_y = self._place(state, data_x, data_y)
+        # host spans (``jax.profiler``): the upload once per call, the
+        # epoch's enqueue and its readout once per epoch
+        with jax.profiler.TraceAnnotation("repro.fit.upload"):
+            data_x, data_y, lens = device_put_shards(shards)
+            state, data_x, data_y = self._place(state, data_x, data_y)
         history = []
         for ep in range(epochs):
             self._epochs_done += 1
-            state, ms = run_epoch(
-                state, data_x, data_y, lens,
-                jax.random.fold_in(self._root, self._epochs_done),
-            )
-            ms = jax.device_get(ms)  # single readout per epoch
-            rec = {k: float(np.mean(v)) for k, v in ms.items()}
-            rec["epoch"] = ep
+            with jax.profiler.TraceAnnotation("repro.fit.dispatch"):
+                state, ms = run_epoch(
+                    state, data_x, data_y, lens,
+                    jax.random.fold_in(self._root, self._epochs_done),
+                )
+            with jax.profiler.TraceAnnotation("repro.fit.readout"):
+                ms = jax.device_get(ms)  # single readout per epoch
+                rec = {k: float(np.mean(v)) for k, v in ms.items()}
+                rec["epoch"] = ep
             if eval_fn is not None:
                 rec.update({f"val_{k}": v for k, v in eval_fn(state).items()})
             history.append(rec)

@@ -325,11 +325,14 @@ def _make_fused(
             feats = fwd_guarded(client_banks, xs, noise_keys)  # [C, b, ...]
         if detached:
             feats = jax.lax.stop_gradient(feats)
-        c, b = feats.shape[0], feats.shape[1]
-        fcat = feats.reshape((c * b,) + feats.shape[2:])
-        out = adapter.server_forward(server_params, fcat)
-        out_cb = out.reshape((c, b) + out.shape[1:])
-        loss = jnp.sum(weights * loss_banked(out_cb, ys))
+        # the trunk and the loss; their backward is named
+        # ``transpose(jvp(trunk))`` by JAX itself
+        with jax.named_scope("trunk"):
+            c, b = feats.shape[0], feats.shape[1]
+            fcat = feats.reshape((c * b,) + feats.shape[2:])
+            out = adapter.server_forward(server_params, fcat)
+            out_cb = out.reshape((c, b) + out.shape[1:])
+            loss = jnp.sum(weights * loss_banked(out_cb, ys))
         return loss, (out_cb, ys)
 
     def trainable_of(state):
@@ -364,16 +367,18 @@ def _make_fused(
         (loss, (out, ycb)), flat_grads = jax.value_and_grad(lf, has_aux=True)(flat)
         # same math as the seed's leaf-wise clip_by_global_norm + update,
         # fp32-reassociated
-        gnorm = jnp.sqrt(jnp.sum(jnp.square(flat_grads)))
-        scale = jnp.minimum(1.0, tc.grad_clip / jnp.maximum(gnorm, 1e-9))
-        updates, new_opt = opt.update(flat_grads * scale, opt_state, flat, step)
+        with jax.named_scope("optimizer"):
+            gnorm = jnp.sqrt(jnp.sum(jnp.square(flat_grads)))
+            scale = jnp.minimum(1.0, tc.grad_clip / jnp.maximum(gnorm, 1e-9))
+            updates, new_opt = opt.update(flat_grads * scale, opt_state, flat, step)
+            new_flat = flat + updates
         # share-weighted per-client means: equals the seed's concat-mix for
         # linear metrics; nonlinear aggregates (rmsle, smape) become
         # weighted per-client means.
         per = metrics_banked(out, ycb)
         metrics = {k: jnp.sum(weights * v) for k, v in per.items()}
         metrics["grad_norm"] = gnorm
-        return flat + updates, new_opt, metrics
+        return new_flat, new_opt, metrics
 
     def step_core(state, xs, ys, rng):
         flat, unravel = ravel_pytree(trainable_of(state))
@@ -673,10 +678,11 @@ def make_epoch_runner(
 
             def step_noise(key):
                 cks = jax.random.split(key, tc.n_clients)
-                gks = guard.keys_for(cks)
-                return jax.vmap(
-                    lambda k: jax.random.normal(k, feat.shape, jnp.float32)
-                )(gks)
+                with jax.named_scope("guard"):
+                    gks = guard.keys_for(cks)
+                    return jax.vmap(
+                        lambda k: jax.random.normal(k, feat.shape, jnp.float32)
+                    )(gks)
 
             draw = jax.jit(jax.vmap(step_noise))
             _noise_draw_cache[feat.shape] = draw

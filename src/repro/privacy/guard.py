@@ -154,7 +154,9 @@ class PrivacyGuard:
     """Composable release policy at the cut: clip → noise → quantize.
 
     ``guard(key, features)`` is pure, jittable and vmappable — the engines
-    vmap it over the stacked client axis. ``dp=None`` is the identity.
+    vmap it over the stacked client axis. ``dp=None`` is the identity. The
+    release's ops (noise draw, clip, noise, quantize) carry the ``guard``
+    named scope: op_name metadata, no run-time cost.
     """
 
     dp: Optional[DPConfig] = None
@@ -192,7 +194,8 @@ class PrivacyGuard:
             # accountant — refuse rather than report a guarantee that
             # does not hold
             assert key is not None, "guard sigma > 0 requires a PRNG key"
-            noise = jax.random.normal(key, features.shape, jnp.float32)
+            with jax.named_scope("guard"):
+                noise = jax.random.normal(key, features.shape, jnp.float32)
         return self.release_with_noise(features, noise)
 
     def release_with_noise(self, features: jnp.ndarray,
@@ -208,17 +211,18 @@ class PrivacyGuard:
         sigma = dp.sigma
         if sigma > 0.0:
             assert noise is not None, "guard sigma > 0 requires pre-drawn noise"
-        if dp.clip_norm is None:
-            # unclipped ⇒ exactly the legacy perturbation (bit-exact shim path)
-            out = features
-            if sigma > 0.0 and noise is not None:
-                out = features + sigma * noise.astype(features.dtype)
-        else:
-            out = _dp_release_op(
-                features, noise,
-                clip_norm=float(dp.clip_norm), sigma=float(sigma),
-                use_kernel=dp.use_kernel, interpret=dp.interpret,
-            )
-        if dp.quantize_bits is not None:
-            out = quantize_ste(out, dp.clip_norm or 1.0, dp.quantize_bits)
+        with jax.named_scope("guard"):
+            if dp.clip_norm is None:
+                # unclipped ⇒ exactly the legacy perturbation (bit-exact shim path)
+                out = features
+                if sigma > 0.0 and noise is not None:
+                    out = features + sigma * noise.astype(features.dtype)
+            else:
+                out = _dp_release_op(
+                    features, noise,
+                    clip_norm=float(dp.clip_norm), sigma=float(sigma),
+                    use_kernel=dp.use_kernel, interpret=dp.interpret,
+                )
+            if dp.quantize_bits is not None:
+                out = quantize_ste(out, dp.clip_norm or 1.0, dp.quantize_bits)
         return out

@@ -18,8 +18,12 @@ dispatches one batch, then advances. No wall-clock, no threads — so the
 whole request lifecycle (admissions, queue-full drops, per-client-cap
 rejections, sheds, batch compositions, cycle latencies, responses) is a
 pure function of ``(canonical state, trace, knobs)`` and replays
-bit-for-bit from the same seed. Wall-clock latencies are measured alongside
-for the benchmark (``benchmarks/serve_perf.py``) but carry no semantics.
+bit-for-bit from the same seed. Wall-clock times are stamped alongside but
+carry no semantics: ``ServeReport.latency_ms`` (admission to answer) and
+``ServeReport.queue_wait_ms`` (push to pop), and, under ``jax.profiler``,
+the host spans ``repro.serve.release`` (one offered request, from the key
+fold-in to the push) and ``repro.serve.batch`` (one cycle's pops through
+routing its answers).
 
 Admission control reuses the training queue's accounting verbatim:
 ``queue_size`` overflow and ``per_client_cap`` rejections are the PR 2/PR 5
@@ -66,14 +70,16 @@ def make_server_batch_forward(adapter: SplitAdapter, mesh=None):
     update half: serving never touches the trunk). Padded slots run on
     zeros and their outputs are simply never routed. ``mesh=`` constrains
     the trunk tensor-parallel over its ``"model"`` axis exactly like every
-    training step (identity on 1-sized/absent axes — bit-exact there).
+    training step (identity on 1-sized/absent axes — bit-exact there). The
+    trunk's ops carry the ``trunk`` named scope.
     """
     shard_trunk = _trunk_sharder(mesh)
 
     @jax.jit
     def forward(server_params, feats):
         server_params = shard_trunk(server_params)
-        return jax.vmap(lambda f: adapter.server_forward(server_params, f))(feats)
+        with jax.named_scope("trunk"):
+            return jax.vmap(lambda f: adapter.server_forward(server_params, f))(feats)
 
     return forward
 
@@ -101,6 +107,8 @@ class ServeReport:
     per_client: List[Dict[str, int]] = dataclasses.field(default_factory=list)
     latency_cycles: Dict[int, int] = dataclasses.field(default_factory=dict)
     latency_ms: Dict[int, float] = dataclasses.field(default_factory=dict)
+    # per answered request: wall time from its push to the pop that took it
+    queue_wait_ms: Dict[int, float] = dataclasses.field(default_factory=dict)
     responses: Optional[Dict[int, np.ndarray]] = None
     features: Optional[Dict[int, np.ndarray]] = None
     queue_stats: Dict[str, int] = dataclasses.field(default_factory=dict)
@@ -267,42 +275,18 @@ class SplitInferenceServer:
         if self.record_features:
             report.features = {}
         arrivals = trace.by_cycle()
-        t = 0
-        t0 = time.perf_counter()
-        while t < trace.horizon or len(queue) > 0:
-            # ---- admissions: this cycle's arrivals release + push
-            for req in arrivals.get(t, ()):
-                c = req.client_id
-                report.offered += 1
-                report.per_client[c]["offered"] += 1
-                idx = rngs[c].integers(0, len(xs[c]), size=self.request_batch)
-                releases[c] += 1  # budget spent whether or not the push lands
-                feats = self._release(c, xs[c][idx], releases[c])
-                if self.record_features:
-                    report.features[req.req_id] = np.asarray(feats)
-                if queue.push(c, feats, req.req_id):
-                    report.accepted += 1
-                    report.per_client[c]["accepted"] += 1
-                    admitted_cycle[req.req_id] = t
-                    admitted_wall[req.req_id] = time.perf_counter()
-                    owner[req.req_id] = c
-                    inflight[c] += 1
-                    max_inflight[c] = max(max_inflight[c], inflight[c])
-                else:
-                    report.dropped += 1
-                    report.per_client[c]["dropped"] += 1
-                    if len(queue) >= self.queue_size:
-                        report.dropped_full += 1
-                    else:  # room in the queue ⇒ the per-client cap rejected
-                        report.dropped_cap += 1
-            # ---- one consumer cycle: batch up to max_batch ready requests,
-            # shedding anything that aged past the deadline on the way
-            batch: List[Tuple[int, Any, int]] = []
+
+        def consume(t: int) -> int:
+            """Batch up to max_batch ready requests, shedding anything that
+            aged past the deadline on the way, run the trunk on them and
+            route the answers; returns the batch's size."""
+            batch: List[Tuple[int, Any, int, float]] = []
             while len(batch) < self.max_batch:
                 item = _pop_with_backoff(queue, 0.0, self.pop_retries,
                                          self.pop_backoff)
                 if item is None:
                     break
+                popped = time.perf_counter()
                 cid, feats, rid = item
                 inflight[cid] -= 1
                 if (self.max_wait is not None
@@ -311,28 +295,70 @@ class SplitInferenceServer:
                     report.per_client[cid]["shed"] += 1
                     admitted_cycle.pop(rid), admitted_wall.pop(rid)
                     continue
-                batch.append((cid, feats, rid))
-            if batch:
-                k = len(batch)
-                feats = jnp.stack([jnp.asarray(f) for _, f, _ in batch])
-                if k < self.max_batch:  # pad to the one compiled shape
-                    feats = jnp.concatenate([
-                        feats,
-                        jnp.zeros((self.max_batch - k,) + feats.shape[1:],
-                                  feats.dtype),
-                    ])
-                outs = jax.device_get(self._batch_fwd(self.server_params, feats))
-                now = time.perf_counter()
-                for i, (cid, _, rid) in enumerate(batch):
-                    if rid in responses:
-                        raise RuntimeError(f"request {rid} answered twice")
-                    responses[rid] = np.asarray(outs[i])
-                    report.answered += 1
-                    report.per_client[cid]["answered"] += 1
-                    report.latency_cycles[rid] = t - admitted_cycle.pop(rid)
-                    report.latency_ms[rid] = (now - admitted_wall.pop(rid)) * 1e3
-                report.batches += 1
-                report.batched_items += k
+                batch.append((cid, feats, rid, popped))
+            if not batch:
+                return 0
+            k = len(batch)
+            feats = jnp.stack([jnp.asarray(f) for _, f, _, _ in batch])
+            if k < self.max_batch:  # pad to the one compiled shape
+                feats = jnp.concatenate([
+                    feats,
+                    jnp.zeros((self.max_batch - k,) + feats.shape[1:],
+                              feats.dtype),
+                ])
+            outs = jax.device_get(self._batch_fwd(self.server_params, feats))
+            now = time.perf_counter()
+            for i, (cid, _, rid, popped) in enumerate(batch):
+                if rid in responses:
+                    raise RuntimeError(f"request {rid} answered twice")
+                responses[rid] = np.asarray(outs[i])
+                report.answered += 1
+                report.per_client[cid]["answered"] += 1
+                report.latency_cycles[rid] = t - admitted_cycle.pop(rid)
+                pushed = admitted_wall.pop(rid)
+                report.latency_ms[rid] = (now - pushed) * 1e3
+                report.queue_wait_ms[rid] = (popped - pushed) * 1e3
+            report.batches += 1
+            report.batched_items += k
+            return k
+
+        t = 0
+        t0 = time.perf_counter()
+        while t < trace.horizon or len(queue) > 0:
+            # ---- admissions: this cycle's arrivals release + push
+            for req in arrivals.get(t, ()):
+                c = req.client_id
+                with jax.profiler.TraceAnnotation(
+                        "repro.serve.release", req_id=req.req_id, client=c):
+                    report.offered += 1
+                    report.per_client[c]["offered"] += 1
+                    idx = rngs[c].integers(0, len(xs[c]), size=self.request_batch)
+                    releases[c] += 1  # budget spent whether or not the push lands
+                    feats = self._release(c, xs[c][idx], releases[c])
+                    if self.record_features:
+                        report.features[req.req_id] = np.asarray(feats)
+                    if queue.push(c, feats, req.req_id):
+                        report.accepted += 1
+                        report.per_client[c]["accepted"] += 1
+                        admitted_cycle[req.req_id] = t
+                        admitted_wall[req.req_id] = time.perf_counter()
+                        owner[req.req_id] = c
+                        inflight[c] += 1
+                        max_inflight[c] = max(max_inflight[c], inflight[c])
+                    else:
+                        report.dropped += 1
+                        report.per_client[c]["dropped"] += 1
+                        if len(queue) >= self.queue_size:
+                            report.dropped_full += 1
+                        else:  # room in the queue ⇒ the per-client cap rejected
+                            report.dropped_cap += 1
+            # ---- one consumer cycle; a cycle that finds requests queued
+            # is one ``repro.serve.batch`` span
+            if len(queue) > 0:
+                with jax.profiler.TraceAnnotation("repro.serve.batch") as span:
+                    span.set_metadata(size=consume(t))
+            else:
+                consume(t)
             t += 1
         report.wall_s = time.perf_counter() - t0
         report.cycles = t
