@@ -36,11 +36,11 @@ def conv2d(p, x, stride=1):
 
 def conv2d_taps(p, x):
     """Stride-1 SAME conv as one matmul per kernel tap (the Pallas privacy
-    kernel's decomposition). einsum lowers to batched GEMM, which vmaps
-    cleanly over the fused trainer's stacked-client-bank axis — XLA:CPU's
-    grouped-conv lowering for a vmapped `conv_general_dilated` is an order
-    of magnitude slower there. Client-side only: the server trunk is never
-    vmapped and a native conv has the cheaper backward."""
+    kernel's decomposition). For one input channel only: each tap's matmul
+    contracts over a single channel, which the TPU compiles to an exact
+    float32 product, where a convolution at default precision would round
+    the raw image to bfloat16. The benchmark's reference writes the privacy
+    layer's first convolution the same way."""
     kh, kw = p["w"].shape[:2]
     ph, pw = kh // 2, kw // 2
     h, w = x.shape[-3], x.shape[-2]
@@ -99,7 +99,29 @@ def init_cnn(key, cfg: CNNConfig, dtype=jnp.float32) -> Dict[str, Any]:
     }
 
 
+def client_conv(p, x):
+    """A privacy-layer convolution, in the form its input's channel count
+    picks: one input channel keeps :func:`conv2d_taps` (exact float32), more
+    run as the native :func:`conv2d`, whose operands default precision
+    rounds to bfloat16 in either form. Under the fused trainer's ``vmap``
+    over the hospital bank the native form becomes one grouped convolution.
+    The scopes ``conv_taps`` and ``conv`` name the form in a trace.
+
+    The native form's input passes an optimization barrier so that it is
+    computed once: fused into the convolution, the previous layer's taps
+    are recomputed per window (MURA's banked first block on a TPU v5e:
+    35 ms fused, 15 + 8 ms apart)."""
+    if x.shape[-1] == 1:
+        with jax.named_scope("conv_taps"):
+            return conv2d_taps(p, x)
+    with jax.named_scope("conv"):
+        return conv2d(p, jax.lax.optimization_barrier(x))
+
+
 def _run_stage(convs, x, conv=conv2d):
+    """Conv + ReLU per layer, then the pool. ``conv`` is the form of each
+    convolution: the trunk's native :func:`conv2d`, or the client's
+    :func:`client_conv`, which picks it per layer from the input channels."""
     for c in convs:
         x = jax.nn.relu(conv(c, x))
     return max_pool(x)
@@ -114,7 +136,9 @@ def client_forward(params, cfg: CNNConfig, x, noise_key=None):
     With ``cfg.use_kernel`` every single-conv stage runs through the fused
     Pallas kernel (conv+ReLU+pool+noise in one VMEM pass); the final stage
     fuses the Gaussian draw on-chip so the kernel and XLA paths see the
-    exact same noise (same key, same post-pool shape).
+    exact same noise (same key, same post-pool shape). Other stages run
+    :func:`client_conv`: a convolution over one input channel as tap
+    matmuls, one over more channels as a native convolution.
     """
     stages = params["client"]["stages"]
     scale = cfg.privacy_noise if noise_key is not None else 0.0
@@ -128,7 +152,7 @@ def client_forward(params, cfg: CNNConfig, x, noise_key=None):
                 interpret=cfg.interpret,
             )
         else:
-            x = _run_stage(convs, x, conv=conv2d_taps)
+            x = _run_stage(convs, x, conv=client_conv)
             if last:
                 x = add_privacy_noise(x, scale, noise_key)
     if not stages:

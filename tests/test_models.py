@@ -1,5 +1,6 @@
 """Model-stack behaviour: decode≡prefill, chunked attention vs naive,
-MoE semantics, SSM scan equivalences, hybrid layer pattern."""
+MoE semantics, SSM scan equivalences, hybrid layer pattern, the CNN
+client's two convolution forms."""
 import dataclasses
 
 import jax
@@ -8,6 +9,7 @@ import numpy as np
 import pytest
 
 from repro.configs import get_config
+from repro.models import cnn
 from repro.models import model as M
 from repro.models import ssm as ssm_mod
 from repro.models.attention import chunked_attention
@@ -137,3 +139,24 @@ def test_privacy_noise_applied_only_with_key():
     a, _ = forward(params, cfg, {"tokens": toks}, OPTS, noise_key=None)
     b, _ = forward(params, cfg, {"tokens": toks}, OPTS, noise_key=jax.random.PRNGKey(7))
     assert float(jnp.max(jnp.abs(a - b))) > 0.0
+
+
+@pytest.mark.parametrize("cin,hw", [(1, 9), (1, 12), (3, 9), (3, 12), (64, 11), (64, 20)])
+def test_client_conv_matches_taps_over_a_bank(cin, hw):
+    """Vmapped over a bank of distinct weights, as the fused trainer runs it,
+    the client convolution is the tap sum: bit for bit with one input
+    channel, within float32 rounding as a native convolution."""
+    n_bank, cout = 4, 16
+    kx, kbias, *kb = jax.random.split(jax.random.PRNGKey(cin * 100 + hw), n_bank + 2)
+    layers = [cnn._init_conv(k, cin, cout) for k in kb]
+    bank = jax.tree.map(lambda *a: jnp.stack(a), *layers)
+    bank["b"] = jax.random.normal(kbias, bank["b"].shape)  # check the bias too
+    x = jax.random.normal(kx, (n_bank, 2, hw, hw, cin))
+    got = np.asarray(jax.jit(jax.vmap(cnn.client_conv))(bank, x))
+    want = np.asarray(jax.jit(jax.vmap(cnn.conv2d_taps))(bank, x))
+    assert got.shape == (n_bank, 2, hw, hw, cout)
+    if cin == 1:
+        np.testing.assert_array_equal(got, want)
+    else:
+        scale = float(np.max(np.abs(want)))
+        np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5 * scale)

@@ -1,4 +1,5 @@
-"""The main path's Pallas kernels compiled for a described TPU v5e chip.
+"""The main path's Pallas kernels and programs compiled for a described TPU
+v5e chip.
 
 Nothing runs: the TPU compiler, which is installed without a chip, refuses
 what Mosaic cannot lower (block shapes off the (8, 128) tiling, too much
@@ -6,6 +7,7 @@ VMEM) at the real widths, which interpret mode never checks. The topology is
 described inside a fixture, never at import, and every compile happens in
 this test's own process.
 """
+import re
 from functools import partial
 
 import jax
@@ -14,6 +16,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.configs.paper_models import COVID_CNN, MURA_VGG19, TABLE1_CNN
+from repro.core.adapters import banked_client_forward, cnn_adapter
 from repro.kernels.dp_release.kernel import dp_release_pallas
 from repro.kernels.privacy_conv.kernel import privacy_conv_pallas
 
@@ -64,6 +67,26 @@ def test_dp_release_compiles_at_cut_width(one_chip, cfg, sigma):
     mem = compiled.memory_analysis()
     row_bytes = 4 * cut[0] * cut[1] * cut[2] * cut[3]
     assert mem.temp_size_in_bytes <= 2 * row_bytes
+
+
+def test_mura_client_release_runs_one_native_conv(one_chip):
+    """MURA's banked privacy layer (4 hospitals x 32 rows at 224x224x1)
+    compiles its 64->64 convolution to one convolution over the bank, and
+    nothing to a per-tap matmul: the one-channel layer's taps are
+    elementwise products."""
+    ad = cnn_adapter(MURA_VGG19)
+    bank = jax.eval_shape(lambda k: ad.init(k)["client"], jax.random.PRNGKey(0))
+    banks = jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct((4,) + s.shape, s.dtype, sharding=one_chip),
+        bank)
+    xs = jax.ShapeDtypeStruct((4, 32, 224, 224, 1), jnp.float32, sharding=one_chip)
+    keys = jax.ShapeDtypeStruct((4, 2), jnp.uint32, sharding=one_chip)
+    hlo = jax.jit(banked_client_forward(ad)).lower(banks, xs, keys).compile().as_text()
+    products = [line for line in hlo.splitlines()
+                if re.search(r" (convolution|dot)\(", line)]
+    assert len(products) == 1, products
+    assert "f32[4,32,224,224,64]" in products[0]
+    assert "/conv/" in products[0] and "dot_general" not in products[0]
 
 
 def test_mura_train_step_fits_one_chip(one_chip):
