@@ -1,7 +1,10 @@
 """Operation and byte counts computed from shapes, kept with the benchmark.
 
-``cnn.py`` counts a split CNN's model FLOPs; ``kernels/<name>.py`` holds one
-kernel's operations and bytes per call, found by the kernel's name."""
+``<name>.py`` counts one model family's model FLOPs per sample in its
+``model_flops(model)``, found by the name that a configuration file gives
+under ``"flops"`` (``harness.model_flops``; ``cnn.py``: the paper's split
+CNNs); ``kernels/<name>.py`` holds one kernel's operations and bytes per
+call, found by the kernel's name."""
 from __future__ import annotations
 
 import importlib.util

@@ -1,7 +1,9 @@
-"""What every cell shares: finding a cell's files by name, the chip check,
-the table of peaks, the timed window and the result line."""
+"""What every cell shares: finding a cell's files by name (its FLOP
+counter and layer map among them), the chip check, the table of peaks, the
+timed window and the result line."""
 from __future__ import annotations
 
+import hashlib
 import importlib.util
 import json
 import sys
@@ -11,6 +13,7 @@ from typing import Callable, Dict, List, Tuple
 
 BENCH = Path(__file__).resolve().parent
 ROOT = BENCH.parent
+TRACED_CACHE = ".jax_cache_traced"  # under ROOT, git-ignored
 
 
 class NoChip(SystemExit):
@@ -46,6 +49,48 @@ def cell_files(bench: dict, workload: str):
     return cell, cfg, traffic, limits
 
 
+def model_flops(cfg: dict) -> dict:
+    """The configuration's model FLOPs per sample (``train``, ``serve``,
+    ...), from ``flops/<name>.py``'s ``model_flops(cfg["model"])``, where
+    the configuration file gives ``"flops": "<name>"``. There is no default:
+    a missing or unknown counter exits here, so that a run fails before its
+    set-up and not after its window."""
+    name = cfg.get("flops")
+    if not name:
+        raise SystemExit(f"bench: configuration {cfg.get('name')!r} names no FLOP "
+                         f"counter (\"flops\": \"<name>\" for {BENCH / 'flops'}/<name>.py)")
+    return load_module("flops", name).model_flops(cfg["model"])
+
+
+def layer_map(runner: str) -> Dict[str, str]:
+    """Program names to layers: ``layers.json``, and ``layers/<runner>.json``
+    where that file exists. The runner's file may add names, not redefine
+    them."""
+    layers = read_json(BENCH / "layers.json")
+    path = BENCH / "layers" / f"{runner}.json"
+    if path.is_file():
+        for name, layer in read_json(path).items():
+            if layers.setdefault(name, layer) != layer:
+                raise SystemExit(f"bench: {path} maps program {name!r} to {layer!r}; "
+                                 f"layers.json maps it to {layers[name]!r}")
+    return layers
+
+
+def traced_cache_dir() -> Path:
+    """The traced run's persistent compilation cache: a directory in the
+    checkout named by a digest of every Python file of the program as
+    imported (``repro``) and of the benchmark, so that two builds never
+    share one."""
+    import repro
+
+    h = hashlib.sha256()
+    for top in (*(Path(p).resolve() for p in repro.__path__), BENCH):
+        for f in sorted(top.rglob("*.py")):
+            h.update(str(f.relative_to(top.parent)).encode() + b"\0")
+            h.update(f.read_bytes())
+    return ROOT / TRACED_CACHE / h.hexdigest()[:16]
+
+
 def metrics_for(bench: dict, kind: str, workload: str) -> List[dict]:
     """The ``end_to_end`` or ``per_layer`` entries that this cell reports."""
     return [m for m in bench[kind]
@@ -56,7 +101,7 @@ def load_module(kind: str, name: str):
     """``<kind>/<name>.py`` under the benchmark, imported by its path."""
     path = BENCH / kind / f"{name}.py"
     if not path.is_file():
-        raise SystemExit(f"bench: no {kind[:-1]} file {path}")
+        raise SystemExit(f"bench: no file {path} ({kind} {name!r})")
     spec = importlib.util.spec_from_file_location(
         f"bench_{kind}_{name}".replace(".", "_").replace("-", "_"), path)
     mod = importlib.util.module_from_spec(spec)

@@ -2,9 +2,10 @@
 time of each named scope and the program's host spans.
 
 The program names its layers itself. Inside the compiled programs,
-``jax.named_scope`` puts ``privacy_layer``, ``guard``, ``trunk`` and
-``optimizer`` into every op's ``op_name`` metadata; JAX names the trunk's
-backward ``transpose(jvp(trunk))``. On the host, ``fit`` and ``serve``
+``jax.named_scope`` puts ``privacy_layer`` (with ``conv`` or ``conv_taps``
+round each of its convolutions), ``guard``, ``trunk`` and ``optimizer``
+into every op's ``op_name`` metadata; JAX names the trunk's backward
+``transpose(jvp(trunk))``. On the host, ``fit`` and ``serve``
 open ``repro.*`` spans: ``repro.fit.upload`` (once a call),
 ``repro.fit.dispatch`` and ``repro.fit.readout`` (once an epoch),
 ``repro.serve.release`` (once an offered request) and ``repro.serve.batch``
@@ -19,8 +20,13 @@ op's time and the op's instruction name: never from an op's own name.
 :func:`layer_times` reduces the events; :func:`readings` gives the
 per-layer numbers.
 
+Each reading is a per-layer metric of the benchmark: ``metrics/<name>.py``
+returns its entry of :func:`readings` over ``run.Context``'s
+``layer_times``, its traced calls' counts and the program's counters.
+
 Run as a script on the chip, it measures one cell as ``run.py --trace 1``
-does and prints those numbers as its last line:
+does and prints the readings, the cell's per-layer metrics, the spans and
+the device time under no scope of the program's as its last line:
 
   python3 bench/layer_trace.py --workload <cell> --seed <n> [--seconds <s>]
 """
@@ -195,6 +201,23 @@ class LayerTimes:
         return sum(v for p, v in self.scope_s.items() if include(p) and not exclude(p))
 
 
+def _busy_before(busy: List[Tuple[int, int]]):
+    """``t -> `` the device's busy nanoseconds before ``t``, over ``busy``:
+    sorted, disjoint intervals. Each query costs a bisection, so that a
+    serving window's thousands of spans over its tens of thousands of
+    device ops are read in well under a second."""
+    starts = [s for s, _ in busy]
+    done = [0]
+    for s, e in busy:
+        done.append(done[-1] + e - s)
+
+    def before(t: int) -> int:
+        k = bisect.bisect_right(starts, t) - 1
+        return done[k] + min(busy[k][1], t) - busy[k][0] if k >= 0 else 0
+
+    return before
+
+
 def layer_times(ev: ScopedEvents) -> LayerTimes:
     """The device self time per scope path and the ``repro.*`` host spans'
     counts, durations and device-idle time, all clipped to ``bench.window``."""
@@ -204,11 +227,12 @@ def layer_times(ev: ScopedEvents) -> LayerTimes:
         if ns:
             scope_ns[path] += ns
     busy = tracing._union([c for _, s, e in ev.ops if (c := tracing._clip(s, e, lo, hi))])
+    busy_before = _busy_before(busy)
     spans: Dict[str, Dict[str, float]] = {}
     for name, s, e in ev.host:
         if not name.startswith(SPAN_PREFIX) or (c := tracing._clip(s, e, lo, hi)) is None:
             continue
-        covered = sum(max(0, min(e2, c[1]) - max(s2, c[0])) for s2, e2 in busy)
+        covered = busy_before(c[1]) - busy_before(c[0])
         sp = spans.setdefault(name, {"count": 0, "seconds": 0.0, "idle_s": 0.0})
         sp["count"] += 1
         sp["seconds"] += (c[1] - c[0]) * 1e-9
@@ -245,6 +269,7 @@ def readings(lt: LayerTimes, totals: dict, counters: dict) -> Dict[str, Optional
     return {
         "train.privacy_layer_device_ms_per_step": ms_per_step(
             lambda p: under(p, "privacy_layer"), lambda p: under(p, "guard")),
+        "train.conv_device_ms_per_step": ms_per_step(lambda p: under(p, "conv")),
         "train.guard_device_ms_per_step": ms_per_step(lambda p: under(p, "guard")),
         "train.trunk_fwd_device_ms_per_step": ms_per_step(
             lambda p: under(p, "trunk"), lambda p: backward_of(p, "trunk")),
@@ -278,9 +303,7 @@ def _parse(argv):
 
 def main(argv=None) -> int:
     import json
-    import shutil
     import sys
-    import tempfile
 
     import harness
     import run
@@ -288,46 +311,27 @@ def main(argv=None) -> int:
     args = _parse(argv)
     bench = harness.load_benchmark()
     cell, cfg, traffic, _ = harness.cell_files(bench, args.workload)
+    flops = harness.model_flops(cfg)
+    layers = harness.layer_map(traffic["runner"])
     try:
         device = harness.require_chips(cell["chips"])
     except harness.NoChip as e:
         print(e, file=sys.stderr)
         return 3
-    from flops.cnn import model_flops
-    from repro.launch.compile_cache import configure_compile_cache
-
-    configure_compile_cache()
+    run.compile_cache(trace=1)
     runner = harness.load_module("runners", traffic["runner"]).Run(cfg, traffic, args.seed)
     runner.setup()
-    waits: List[float] = []
-    server = getattr(runner, "server", None)
-    if server is not None:  # the serving runner: keep each report's queue wait
-        def serve(*a, _serve=server.serve, **kw):
-            rep = _serve(*a, **kw)
-            waits.extend(getattr(rep, "queue_wait_ms", {}).values())
-            return rep
-        server.serve = serve
     harness.window(runner.unit, seconds=args.seconds)
-    counters = {**runner.counters(), "queue_wait_ms": list(waits)}
-    log_dir = tempfile.mkdtemp(prefix="layer-trace-")
-    try:
-        totals, path = tracing.capture(
-            lambda: harness.window(runner.unit, calls=runner.traced_units()), log_dir)
-        ev = load(path)
-    finally:
-        shutil.rmtree(log_dir, ignore_errors=True)
-    lt = layer_times(ev)
-    ctx = run.Context(tracing.reduce(ev), totals, counters, model_flops(cfg["model"]),
-                      harness.peak(device["kind"]),
-                      harness.read_json(harness.BENCH / "layers.json"))
-    existing = {m["name"]: harness.load_module("metrics", m["name"]).read(ctx)
-                for m in harness.metrics_for(bench, "per_layer", args.workload)}
-    layers = ("privacy_layer", "guard", "trunk", "optimizer")
+    ctx = run.profile(runner, device, flops, layers, runner.counters())
+    lt, totals = ctx.layer_times, ctx.totals
+    metrics = {m["name"]: harness.load_module("metrics", m["name"]).read(ctx)
+               for m in harness.metrics_for(bench, "per_layer", args.workload)}
+    scopes = ("privacy_layer", "guard", "trunk", "optimizer")
     unscoped = sorted(((p, v) for p, v in lt.scope_s.items()
-                       if not any(under(p, k) for k in layers)), key=lambda kv: -kv[1])
+                       if not any(under(p, k) for k in scopes)), key=lambda kv: -kv[1])
     print(json.dumps({
         "workload": args.workload, "seed": args.seed, "device": device,
-        "readings": readings(lt, totals, counters), "existing": existing,
+        "readings": readings(lt, totals, ctx.counters), "metrics": metrics,
         "totals": {k: v for k, v in totals.items() if k != "call_seconds"},
         "spans": lt.spans, "window_s": lt.window_s, "idle_s": lt.idle_s,
         "device_self_s": sum(lt.scope_s.values()),

@@ -1,6 +1,7 @@
 """Model FLOP/s utilization of the whole training step: the model FLOPs a
-sample needs (``flops/cnn.py``), times the samples trained per second in
-the traced window, over the chip's bf16 peak."""
+sample needs, by the configuration's counter (``flops/<name>.py``), times
+the samples trained per second in the traced window, over the chip's bf16
+peak."""
 
 
 def read(ctx):

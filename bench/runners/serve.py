@@ -52,6 +52,7 @@ class Run:
         self.kept = []  # (trace seed, arrivals, {req_id: answer})
         self.ledger_gap = 0
         self.latency_ms = []
+        self.queue_wait_ms = []
 
     def setup(self) -> None:
         from repro.serving.server import SplitInferenceServer
@@ -84,6 +85,7 @@ class Run:
             rep = self.server.serve(trace, self.shards)
         self.ledger_gap += abs(rep.offered - rep.answered - rep.dropped - rep.shed)
         self.latency_ms.extend(rep.latency_ms.values())
+        self.queue_wait_ms.extend(rep.queue_wait_ms.values())
         pick = np.random.default_rng((self.seed, self.calls, 5))
         ids = sorted(rep.responses)
         take = pick.choice(len(ids), size=min(t["checked_per_call"], len(ids)),
@@ -97,7 +99,8 @@ class Run:
         return self.traffic["traced_calls"]
 
     def counters(self) -> dict:
-        return {"latency_ms": list(self.latency_ms)}
+        return {"latency_ms": list(self.latency_ms),
+                "queue_wait_ms": list(self.queue_wait_ms)}
 
     def end_to_end(self, totals: dict) -> dict:
         return {"serve_requests_per_s": totals["answered"] / totals["seconds"]}

@@ -45,7 +45,7 @@ SERVE_LIMITS = {"answer_vs_bf16": 0.05, "answer_rms_vs_bf16": 0.05, "ledger": 0}
 
 def tiny_config(name: str, model: str, dataset: str, hospitals: int, shares,
                 batch: int) -> dict:
-    return {"name": name, "source": "test",
+    return {"name": name, "source": "test", "flops": "cnn",
             "dataset": dataset, "model": TINY_MODELS[model],
             "hospitals": hospitals, "shares": shares, "server_batch": batch,
             "mode": "detached", "precision": "float32",
@@ -116,7 +116,14 @@ class Checkout:
 
 @pytest.fixture
 def checkout(tmp_path, monkeypatch):
+    """The tiny cells' checkout. A traced run points JAX's persistent
+    compilation cache at the checkout; it is pointed back after."""
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache
+
     import harness
+
+    cache_dir = jax.config.jax_compilation_cache_dir
 
     co = Checkout(tmp_path)
     monkeypatch.setattr(harness, "ROOT", tmp_path)
@@ -138,7 +145,9 @@ def checkout(tmp_path, monkeypatch):
     co.add_cell("tiny-vgg-serve",
                 tiny_config("tiny-vgg", "tiny-vgg", "mura_xray", 4, [0.4, 0.3, 0.2, 0.1], 8),
                 "tiny-serve", SERVE_LIMITS)
-    return co
+    yield co
+    jax.config.update("jax_compilation_cache_dir", cache_dir)
+    compilation_cache.reset_cache()
 
 
 def run_cell(capsys, workload: str, seed: int = 2**31 + 17, trace: int = 0,

@@ -47,6 +47,13 @@ def test_covid_cnn_flops_match_a_hand_count():
     # with the first trunk layer's input gradient counted too
 
 
+@pytest.mark.parametrize("name", ["mura-vgg19", "covid-cnn"])
+def test_a_configuration_names_its_flop_counter(name):
+    cfg = harness.read_json(harness.BENCH / "configs" / f"{name}.json")
+    assert cfg["flops"] == "cnn"
+    assert harness.model_flops(cfg) == model_flops(cfg["model"])
+
+
 def test_peak_lookup_refuses_an_unknown_device():
     assert harness.peak("TPU v5 lite")["bf16_flops_per_s"] == 197e12
     with pytest.raises(KeyError):

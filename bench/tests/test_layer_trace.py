@@ -59,6 +59,27 @@ def test_scopes_and_their_backward():
     assert r["serve.queue_wait_p95_ms"] is None
 
 
+def test_the_privacy_layers_native_convolution():
+    # the privacy layer's first convolution runs as taps (20 ns), its second
+    # natively under scope ``conv`` (60 ns), then relu and pool (20 ns); the
+    # trunk's convolution primitive is named ``conv_general_dilated`` and is
+    # under no ``conv`` scope
+    body = "jit(_run_epoch_scan)/while/body/closed_call"
+    ops = [("t", 0, 20), ("c", 20, 80), ("r", 80, 100), ("k", 100, 300)]
+    scopes = [f"{body}/vmap(privacy_layer)/conv_taps/mul",
+              f"{body}/vmap(privacy_layer)/conv/conv_general_dilated",
+              f"{body}/vmap(privacy_layer)/max",
+              f"{body}/jvp(trunk)/conv_general_dilated"]
+    lt = lt_mod.layer_times(events(ops, scopes, [("bench.window", 0, 300)]))
+    r = lt_mod.readings(lt, {"steps": 2}, {})
+    ns = 1e-6  # ns in ms
+    assert r["train.conv_device_ms_per_step"] == pytest.approx(60 * ns / 2)
+    assert r["train.privacy_layer_device_ms_per_step"] == pytest.approx(100 * ns / 2)
+    assert r["train.trunk_fwd_device_ms_per_step"] == pytest.approx(200 * ns / 2)
+    # no steps counted (a serving cell): no per-step reading
+    assert lt_mod.readings(lt, {}, {})["train.conv_device_ms_per_step"] is None
+
+
 def test_scope_matching():
     assert lt_mod.under("jit(f)/while/body/vmap(privacy_layer)/mul", "privacy_layer")
     assert lt_mod.under("jit(f)/transpose(jvp(trunk))/conv", "trunk")
@@ -90,6 +111,29 @@ def test_idle_time_inside_a_span_that_crosses_the_window_edge():
     assert r["train.upload_idle_ms_per_call"] == pytest.approx(50e-6)
     # no scope paths in the trace: the scope metrics read nothing
     assert r["train.trunk_fwd_device_ms_per_step"] is None
+
+
+def test_span_idle_time_over_many_ops():
+    # overlapping device ops and spans, some across the window's edges: each
+    # span's idle time is its length in the window less the busy time in it,
+    # summed op by op
+    import random
+
+    rnd = random.Random(7)
+    ops = [("op", s, s + rnd.randrange(1, 900))
+           for s in sorted(rnd.randrange(-2000, 102_000) for _ in range(500))]
+    spans = [("repro.x", s, s + rnd.randrange(1, 4000))
+             for s in (rnd.randrange(-3000, 101_000) for _ in range(300))]
+    lt = lt_mod.layer_times(events(ops, [""] * len(ops), [("bench.window", 0, 100_000)] + spans))
+    busy = tracing._union([c for _, s, e in ops if (c := tracing._clip(s, e, 0, 100_000))])
+    idle, count = 0.0, 0
+    for _, s, e in spans:
+        if c := tracing._clip(s, e, 0, 100_000):
+            covered = sum(max(0, min(e2, c[1]) - max(s2, c[0])) for s2, e2 in busy)
+            idle += (c[1] - c[0] - covered) * 1e-9
+            count += 1
+    assert lt.spans["repro.x"]["count"] == count > 250
+    assert lt.spans["repro.x"]["idle_s"] == idle
 
 
 def test_serving_readings():
@@ -195,3 +239,6 @@ def test_probe_runs_a_tiny_cell(checkout, capsys, workload, runner):
         assert r["serve.queue_wait_p95_ms"] is not None
         assert r["serve.release_host_ms_per_request"] > 0
     assert r["train.trunk_fwd_device_ms_per_step"] is None
+    # each reading of the cell's runner is its per-layer metric, read alike
+    own = {k: v for k, v in r.items() if k.startswith(runner + ".")}
+    assert own and {k: out["metrics"][k] for k in own} == own
